@@ -1,10 +1,9 @@
 """Distribution: sharding rules, expert parallelism, gradient compression."""
 
-from .compat import shard_map
 from .sharding import (batch_pspecs, cache_pspecs, optimizer_pspecs,
                        param_pspec, params_pspecs, to_named)
 
 __all__ = [
     "batch_pspecs", "cache_pspecs", "optimizer_pspecs", "param_pspec",
-    "params_pspecs", "shard_map", "to_named",
+    "params_pspecs", "to_named",
 ]

@@ -29,7 +29,6 @@ from jax.sharding import PartitionSpec as P
 from ..configs.base import ModelConfig
 from ..models.common import _ACTS
 from ..models.moe import router_probs
-from .compat import shard_map
 
 
 def _ep_axes(mesh, n_experts: int) -> Tuple[str, ...]:
@@ -133,7 +132,7 @@ def apply_moe_ep(params, x, cfg: ModelConfig, *, mesh=None):
 
     x_spec = P(batch_axes if batch_axes else None, seq_axis, None)
     w_spec = P(ep if len(ep) > 1 else ep[0], None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
         out_specs=x_spec, check_vma=False)

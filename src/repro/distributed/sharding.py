@@ -225,11 +225,13 @@ def batch_pspecs(batch_specs: PyTree, mesh: Mesh) -> PyTree:
 def cache_pspecs(cfg: ModelConfig, cache_shape: PyTree, mesh: Mesh) -> PyTree:
     """Decode-cache shardings.
 
-    Full-length ATTN KV caches (B, S, Hkv, D) shard batch over
-    ("pod","data") and *sequence* over "model" — the flash-decode layout
-    (DESIGN.md §5) that sidesteps kv_heads < model_axis.  Ring buffers,
-    MLA latent caches and recurrent states shard batch only (they are
-    small; the latent/recurrent state is shared across heads).
+    Full-length ATTN KV caches — head-major self-attention k/v
+    (B, Hkv, S, D) and cross-attention memory (B, M, Hkv, D) — shard
+    batch over ("pod","data") and *sequence* over "model": the
+    flash-decode layout (DESIGN.md §5) that sidesteps kv_heads <
+    model_axis.  Ring buffers, MLA latent caches and recurrent states
+    shard batch only (they are small; the latent/recurrent state is
+    shared across heads).
     """
     flat, treedef = jax.tree_util.tree_flatten_with_path(cache_shape)
     specs = []
@@ -242,11 +244,13 @@ def cache_pspecs(cfg: ModelConfig, cache_shape: PyTree, mesh: Mesh) -> PyTree:
         axes0 = _divisible_batch_axes(mesh, dims[0]) if dims else ()
         axes = axes0 if axes0 else None
         if re.search(r"\['(k|v|cross_k|cross_v)'\]$", ps) and len(dims) == 4:
-            seq = dims[1]
+            head_major = re.search(r"\['(k|v)'\]$", ps) is not None
+            seq = dims[2] if head_major else dims[1]
             seq_axis = _maybe(mesh, seq, MODEL_AXIS)
             if window and seq <= window:
                 seq_axis = None                    # ring buffers replicate S
-            spec = P(*lead, axes, seq_axis, None, None)
+            spec = (P(*lead, axes, None, seq_axis, None) if head_major
+                    else P(*lead, axes, seq_axis, None, None))
         elif re.search(r"\['(c_kv|k_rope)'\]$", ps) and len(dims) == 3:
             spec = P(*lead, axes, _maybe(mesh, dims[1], MODEL_AXIS), None)
         elif len(dims) >= 1:

@@ -1,18 +1,25 @@
 """Flash-decode Pallas TPU kernel: one query token vs a long KV cache.
 
-Tiling: grid = (batch, Sk/block_kv) with the KV dimension sequential;
-all H query heads are processed together per tile (decode q is tiny:
-H×D ≤ 32×128).  The per-batch valid length masks ring/partially-filled
-caches.  GQA is computed by reshaping q to (Hkv, rep·D) groups so each
-KV tile is read once.
+Layout: the cache is head-major, ``(B, Hkv, S, D)``, so every KV tile
+is a plain 2-D ``(block_kv, D)`` slab — no kv-head axis in the sublane
+dimension, which Mosaic would pad to a full tile (16× for Hkv=1 in
+bf16).  The ``rep = H // Hkv`` query heads of one GQA group arrive as a
+``(rep, D)`` tile, so each KV tile is read once per group.
+
+Tiling: grid = (batch, kv_heads, S/block_kv) with the KV dimension
+sequential; the online-softmax state lives in 2-D VMEM scratch.  The
+per-sequence valid lengths are scalar-prefetched into SMEM: they mask
+ring/partially-filled caches, skip the compute of tiles past the end,
+and clamp the KV index map so those tiles are never fetched.
 
 This kernel is the TPU analogue of the paper's "intra-op parallelism"
 for decode: the KV cache's *length* dimension is what a thin instance
 shards across its chips (DESIGN.md §5), and within one chip this kernel
 tiles the same axis through VMEM.
 
-VMEM per step (defaults block_kv=512, Hkv=8, D=128, bf16):
-  k,v tiles 2×512×8×128×2B = 2 MiB + q/acc fp32 (H×D) ≈ 2.2 MiB.
+VMEM per step (gemma3-1b: block_kv=1024, D=256, bf16): k,v tiles
+double-buffered 4×1024×256×2B = 2 MiB, plus the (rep, bk) f32 scores
+and the (rep, D) f32 accumulator.
 """
 
 from __future__ import annotations
@@ -26,15 +33,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 NEG_INF = -0.7 * float(np.finfo(np.float32).max)
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, block_kv: int, kv_tiles: int, rep: int,
-                   scale: float):
-    ki = pl.program_id(1)
+                   acc_scr, *, block_kv: int, scale: float):
+    b, ki = pl.program_id(0), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -42,41 +46,31 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[0]
+    length = len_ref[b]
     k_lo = ki * block_kv
 
     @pl.when(k_lo < length)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)               # (H, D)
-        k = k_ref[0].astype(jnp.float32)               # (bk, Hkv, D)
-        v = v_ref[0].astype(jnp.float32)
-        H, D = q.shape
-        Hkv = k.shape[1]
-        qg = q.reshape(Hkv, rep, D)
-        # scores (Hkv, rep, bk)
-        s = jax.lax.dot_general(
-            qg, k.transpose(1, 2, 0),
-            (((2,), (1,)), ((0,), (0,))),
+        s = jax.lax.dot_general(                       # (rep, bk)
+            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos < length, s, NEG_INF)
-        m_prev = m_scr[...]                            # (H,)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2).reshape(H))
-        p = jnp.exp(s - m_new.reshape(Hkv, rep)[..., None])
+        m_prev = m_scr[...]                            # (rep, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        # (Hkv, rep, bk) @ (Hkv, bk, D) → (Hkv, rep, D)
-        pv = jax.lax.dot_general(
-            p, v.transpose(1, 0, 2),
-            (((2,), (1,)), ((0,), (0,))),
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(                      # (rep, D)
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=2).reshape(H)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv.reshape(H, D)
+        acc_scr[...] = alpha * acc_scr[...] + pv
         m_scr[...] = m_new
 
-    @pl.when(ki == kv_tiles - 1)
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def _validate(q, k_cache, v_cache, lengths, block_kv: int) -> None:
@@ -87,14 +81,14 @@ def _validate(q, k_cache, v_cache, lengths, block_kv: int) -> None:
             f"decode_attention: q must be (B, 1, H, D), got {q.shape}")
     if k_cache.ndim != 4 or v_cache.ndim != 4:
         raise ValueError(
-            "decode_attention: caches must be (B, S, Hkv, D), got "
+            "decode_attention: caches must be (B, Hkv, S, D), got "
             f"k={k_cache.shape} v={v_cache.shape}")
     if k_cache.shape != v_cache.shape:
         raise ValueError(
             f"decode_attention: k/v cache shapes differ: "
             f"{k_cache.shape} vs {v_cache.shape}")
     B, _, H, D = q.shape
-    Bk, S, Hkv, Dk = k_cache.shape
+    Bk, Hkv, S, Dk = k_cache.shape
     if Bk != B:
         raise ValueError(
             f"decode_attention: batch mismatch: q has B={B}, cache has "
@@ -124,39 +118,48 @@ def _validate(q, k_cache, v_cache, lengths, block_kv: int) -> None:
 
 def decode_attention(q, k_cache, v_cache, lengths, *, block_kv: int = 512,
                      interpret: bool = False):
-    """q: (B, 1, H, D); caches: (B, S, Hkv, D); lengths: (B,) int32.
+    """q: (B, 1, H, D); caches: (B, Hkv, S, D); lengths: (B,) int32.
 
     Returns (B, 1, H, D).  Cache positions >= lengths[b] are masked.
     """
     _validate(q, k_cache, v_cache, lengths, block_kv)
     B, _, H, D = q.shape
-    S, Hkv = k_cache.shape[1], k_cache.shape[2]
+    Hkv, S = k_cache.shape[1], k_cache.shape[2]
     rep = H // Hkv
     block_kv = min(block_kv, S)
     kv_tiles = S // block_kv
-    scale = 1.0 / math.sqrt(D)
+
+    def q_map(b, h, ki, lens):
+        return b, h, 0, 0
+
+    def kv_map(b, h, ki, lens):
+        # tiles wholly past the valid length repeat the last needed block
+        # index, so the pipeline skips their DMA
+        last = jnp.maximum(lens[b] - 1, 0) // block_kv
+        return b, h, jnp.minimum(ki, last), 0
 
     kernel = functools.partial(_decode_kernel, block_kv=block_kv,
-                               kv_tiles=kv_tiles, rep=rep, scale=scale)
+                               scale=1.0 / math.sqrt(D))
     out = pl.pallas_call(
         kernel,
-        grid=(B, kv_tiles),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, ki: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, H, D), lambda b, ki: (b, 0, 0)),
-            pl.BlockSpec((1, block_kv, Hkv, D), lambda b, ki: (b, ki, 0, 0)),
-            pl.BlockSpec((1, block_kv, Hkv, D), lambda b, ki: (b, ki, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, ki: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, kv_tiles),
+            in_specs=[
+                pl.BlockSpec((None, None, rep, D), q_map),
+                pl.BlockSpec((None, None, block_kv, D), kv_map),
+                pl.BlockSpec((None, None, block_kv, D), kv_map),
+            ],
+            out_specs=pl.BlockSpec((None, None, rep, D), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((rep, 1), jnp.float32),
+                pltpu.VMEM((rep, 1), jnp.float32),
+                pltpu.VMEM((rep, D), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q[:, 0], k_cache, v_cache)
-    return out[:, None]
+    )(lengths.astype(jnp.int32), q.reshape(B, Hkv, rep, D), k_cache,
+      v_cache)
+    return out.reshape(B, 1, H, D)

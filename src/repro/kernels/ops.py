@@ -1,10 +1,10 @@
 """Jitted public wrappers for the Pallas kernels.
 
-Each op auto-selects interpret mode off-TPU (this container is CPU-only:
-kernels execute their bodies in Python via the Pallas interpreter, which
-is how they are validated against the jnp oracles in ref.py), pads
-ragged shapes to tile multiples, and exposes the same signatures the
-model code uses.
+Each op compiles its kernel for the TPU, or runs it in interpret mode on
+the CPU backend (where the tests validate the kernel bodies against the
+jnp oracles in ref.py); any other backend is an error, so no run can
+fall back to the interpreter without saying so.  The ops pad ragged
+shapes to tile multiples and expose the signatures the model code uses.
 """
 
 from __future__ import annotations
@@ -21,7 +21,13 @@ from .ssd_scan import ssd_scan as _ssd_scan
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True on the CPU backend (the test platform), False on the TPU."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels target the TPU (or the CPU interpreter in "
+            f"tests); the {backend!r} backend has neither")
+    return backend == "cpu"
 
 
 def _pad_to(x, multiple: int, axis: int):
@@ -59,11 +65,12 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 @functools.partial(jax.jit, static_argnames=("block_kv",))
 def decode_attention(q, k_cache, v_cache, lengths, *, block_kv: int = 512):
-    """Flash-decode against a KV cache with per-batch valid lengths."""
-    S = k_cache.shape[1]
+    """Flash-decode against a head-major (B, Hkv, S, D) KV cache with
+    per-batch valid lengths."""
+    S = k_cache.shape[2]
     bkv = min(block_kv, S)
-    kp, _ = _pad_to(k_cache, bkv, 1)
-    vp, _ = _pad_to(v_cache, bkv, 1)
+    kp, _ = _pad_to(k_cache, bkv, 2)
+    vp, _ = _pad_to(v_cache, bkv, 2)
     return _decode_attention(q, kp, vp, lengths, block_kv=bkv,
                              interpret=_interpret())
 

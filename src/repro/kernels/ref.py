@@ -25,19 +25,19 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0):
 def decode_attention_ref(q, k_cache, v_cache, lengths):
     """Oracle for kernels.decode_attention.
 
-    q: (B, 1, H, D); caches: (B, S, Hkv, D); lengths: (B,) valid kv counts.
+    q: (B, 1, H, D); caches: (B, Hkv, S, D); lengths: (B,) valid kv counts.
     """
     B, _, H, D = q.shape
-    S, Hkv = k_cache.shape[1], k_cache.shape[2]
+    Hkv, S = k_cache.shape[1], k_cache.shape[2]
     rep = H // Hkv
-    k = jnp.repeat(k_cache, rep, axis=2)
-    v = jnp.repeat(v_cache, rep, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+    k = jnp.repeat(k_cache, rep, axis=1)
+    v = jnp.repeat(v_cache, rep, axis=1)
+    s = jnp.einsum("bqhd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) / math.sqrt(D)
     valid = jnp.arange(S)[None, None, None, :] < lengths[:, None, None, None]
     s = jnp.where(valid, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v).astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bqhd", p.astype(v.dtype), v).astype(q.dtype)
 
 
 def ssd_scan_ref(x, dt, a_log, B_in, C_in, *, chunk: int = 64):

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _rglru_kernel(a_ref, b_ref, y_ref, h_scr, *, block_s: int):
     si = pl.program_id(2)
@@ -68,7 +66,7 @@ def rglru_scan(a, b, *, block_s: int = 128, block_w: int = 512,
                                lambda bb, w, s: (bb, s, w)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -1,8 +1,7 @@
 """Launchers: production mesh, multi-pod dry-run, train/serve CLIs, and
 the scenario-driven serving benchmark (``bench_serving``).
 
-NOTE: importing ``dryrun``/``profile_tpu`` sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` and must happen
-before any other jax initialization; ``mesh``/``hlo_analysis`` are safe
-to import anywhere.
+Importing any of them changes no environment: ``dryrun``, ``hillclimb``
+and ``profile_tpu`` set ``XLA_FLAGS`` (512 placeholder host devices) in
+their ``main()``, before JAX initializes a backend.
 """

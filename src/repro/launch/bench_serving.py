@@ -56,9 +56,10 @@ are derived from the measured capacity and then capped
 (``--real-rate-cap``) so the Python-level event machinery is not the
 bottleneck being measured.
 
-``--execution real --real-model lm-tiny`` selects the **autoregressive
-LM path** (``repro.models.serve_lm``): a scaled-down gemma3-style
-decoder served through the Pallas flash/decode attention kernels, split
+``--execution real --real-model gemma3-1b`` (or ``lm-tiny``, its
+CPU-size reduction) selects the **autoregressive LM path**
+(``repro.models.serve_lm``): a gemma3 decoder served through the Pallas
+flash/decode attention kernels, split
 into a prefill pool and a decode pool (two ``PackratServer``\\ s routing
 runner cells by phase) with a decode-step continuation chain
 (``--lm-decode-steps`` tokens per prompt).  ``static`` time-shares one
@@ -142,6 +143,7 @@ from ..serving.fabric import feed_fabric_trace
 from ..serving.fastsim import (FastLoop, feed_multi_model_trace,
                                feed_single_model_trace)
 from ..serving.workloads import TraceWorkload
+from .compile_cache import configure_compile_cache
 
 POLICIES = ("static", "packrat")
 DISPATCHES = ("sync", "continuous")
@@ -1120,6 +1122,7 @@ def _emit_report(report: Dict[str, object], out: Optional[str]) -> None:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    configure_compile_cache()
     ap = argparse.ArgumentParser(
         description="Scenario-driven serving benchmark "
                     "(static baseline vs adaptive Packrat)")
@@ -1182,9 +1185,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--real-model", default="mlp-tiny",
                     help="model for --execution real: a micro model "
                          "(repro.models.micro registry) or an "
-                         "autoregressive LM (repro.models.serve_lm, "
-                         "e.g. lm-tiny — switches to phase-split "
-                         "prefill/decode serving)")
+                         "autoregressive LM (repro.models.serve_lm: "
+                         "gemma3-1b on the TPU, lm-tiny on the CPU — "
+                         "switches to phase-split prefill/decode "
+                         "serving)")
     ap.add_argument("--lm-decode-steps", type=int, default=8,
                     help="decode steps per prompt before EOS for LM "
                          "real models (the decode continuation chain)")

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture × shape × mesh).
 
 For each cell this driver performs:
@@ -27,6 +24,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import pathlib
 import sys
 import time
@@ -35,13 +33,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
-# persistent compilation cache: repeated lowers (differencing reruns,
-# hillclimb iterations) hit disk instead of recompiling
-_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / "results" / "xla_cache"
-_CACHE_DIR.mkdir(parents=True, exist_ok=True)
-jax.config.update("jax_compilation_cache_dir", str(_CACHE_DIR))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
 
 from ..configs import SHAPES, ShapeConfig, all_configs, applicable_shapes, get_config
 from ..configs.base import ModelConfig
@@ -53,10 +44,21 @@ from ..models import build_model
 from ..models.lm import param_count
 from ..training.optimizer import AdamWConfig, init_adamw
 from ..training.train_loop import TrainConfig, make_train_step
+from .compile_cache import configure_compile_cache
 from .hlo_analysis import ProgramCost, program_cost, roofline_from_cost
 from .mesh import make_production_mesh
 
 RESULTS_DIR = pathlib.Path(__file__).resolve().parents[3] / "results" / "dryrun"
+
+
+def setup_host() -> None:
+    """512 placeholder host devices for the production meshes, and the
+    persistent compilation cache, so repeated lowers (differencing
+    reruns, hillclimb iterations) hit disk instead of recompiling.  Must
+    run before JAX initializes a backend."""
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    configure_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
 
 
 # --------------------------------------------------------------------- #
@@ -290,6 +292,7 @@ def main(argv=None) -> int:
                     help="skip cells whose result JSON already exists OK")
     ap.add_argument("--out", default=str(RESULTS_DIR))
     args = ap.parse_args(argv)
+    setup_host()
 
     outdir = pathlib.Path(args.out)
     outdir.mkdir(parents=True, exist_ok=True)

@@ -1,6 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """§Perf hillclimb driver: baseline vs optimized lowering per cell.
 
 Each iteration is a (hypothesis → change → re-lower → re-analyse) cycle
@@ -21,7 +18,7 @@ import pathlib
 import sys
 
 from ..configs import get_config
-from .dryrun import RESULTS_DIR, analyze_cell
+from .dryrun import RESULTS_DIR, analyze_cell, setup_host
 
 OPTS = {
     "seq_sharding": dict(seq_sharding=True),
@@ -43,6 +40,7 @@ def main(argv=None) -> int:
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--skip-validation", action="store_true")
     args = ap.parse_args(argv)
+    setup_host()
 
     arch, shape = args.cell.split(":")
     from ..configs import SHAPES

@@ -1,9 +1,9 @@
 """Production mesh construction.
 
 A function (not a module-level constant) so importing this module never
-touches jax device state; the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` *before* any
-jax import to fabricate placeholder devices.
+touches jax device state; the dry-run's ``main()`` sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before JAX
+initializes a backend, to fabricate placeholder devices.
 """
 
 from __future__ import annotations
@@ -11,18 +11,10 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed after jax 0.4.37; every axis defaults to Auto there
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _axis_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where supported, nothing otherwise."""
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

@@ -1,6 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """Packrat's profiler on TPU: compile-time L[t,b] tables from sub-meshes.
 
 The paper measures ⟨1,t,b⟩ wall-clock latencies; the TPU analogue lowers
@@ -17,6 +14,7 @@ instance slices must tile the pod.
 
 import argparse
 import json
+import os
 import pathlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -120,6 +118,9 @@ def main(argv=None) -> int:
     ap.add_argument("--batches", type=int, nargs="+",
                     default=[1, 4, 16, 64])
     args = ap.parse_args(argv)
+    # placeholder host devices for the sub-meshes; before any backend
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
     prof = TPUPackratProfiler(args.arch, seq_len=args.seq)
     print("t,b,compute_s,memory_s,collective_s,L_s")
     for t in args.chips:

@@ -112,12 +112,15 @@ def _attn_cache_len(cfg: ModelConfig, kind: str, max_len: int) -> int:
 
 def init_attn_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
                     memory_len: int = 0) -> Dict:
+    """Self-attention k/v are head-major, (B, Hkv, L, Dh): each kv head's
+    sequence is one contiguous (L, Dh) slab, the layout the decode kernel
+    tiles.  Cross-attention memory stays (B, M, Hkv, Dh)."""
     dtype = _dtype(cfg)
     Hkv, Dh = cfg.n_kv_heads, cfg.resolved_head_dim
     L = _attn_cache_len(cfg, kind, max_len)
     cache = {
-        "k": jnp.zeros((batch, L, Hkv, Dh), dtype),
-        "v": jnp.zeros((batch, L, Hkv, Dh), dtype),
+        "k": jnp.zeros((batch, Hkv, L, Dh), dtype),
+        "v": jnp.zeros((batch, Hkv, L, Dh), dtype),
     }
     if kind == DEC:
         cache["cross_k"] = jnp.zeros((batch, memory_len, Hkv, Dh), dtype)
@@ -125,26 +128,30 @@ def init_attn_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
     return cache
 
 
-def _write_full_cache(cache_arr, new, pos):
-    """Write a (B,S,...) slab at sequence offset pos."""
+_KV_SEQ_AXIS = 2    # sequence axis of the head-major (B, Hkv, L, Dh) k/v
+
+
+def _write_full_cache(cache_arr, new, pos, axis: int = 1):
+    """Write a slab at sequence offset pos along ``axis``."""
     return jax.lax.dynamic_update_slice_in_dim(cache_arr, new.astype(
-        cache_arr.dtype), pos, axis=1)
+        cache_arr.dtype), pos, axis=axis)
 
 
 def _write_ring(cache_arr, new, pos, window):
-    """Write one token at slot pos % window (decode)."""
+    """Write one head-major token at slot pos % window (decode)."""
     slot = jnp.asarray(pos) % window
-    return jax.lax.dynamic_update_slice_in_dim(cache_arr, new.astype(
-        cache_arr.dtype), slot, axis=1)
+    return _write_full_cache(cache_arr, new, slot, _KV_SEQ_AXIS)
 
 
 def _prefill_ring(cache_arr, k_seq, window):
-    """Store the last `window` tokens so that token p sits in slot p%window."""
-    S = k_seq.shape[1]
+    """Store the last `window` tokens of a head-major (B, Hkv, S, Dh)
+    sequence so that token p sits in slot p%window."""
+    S = k_seq.shape[_KV_SEQ_AXIS]
     if S <= window:
-        return _write_full_cache(cache_arr, k_seq, 0)
-    tail = k_seq[:, -window:]
-    return jnp.roll(tail.astype(cache_arr.dtype), shift=S % window, axis=1)
+        return _write_full_cache(cache_arr, k_seq, 0, _KV_SEQ_AXIS)
+    tail = k_seq[:, :, -window:]
+    return jnp.roll(tail.astype(cache_arr.dtype), shift=S % window,
+                    axis=_KV_SEQ_AXIS)
 
 
 def apply_attn_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
@@ -160,12 +167,13 @@ def apply_attn_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
         assert cache is not None and pos is not None
         q, k, v = _qkv(params["attn"], h, cfg, kind,
                        jnp.full((1,), pos, jnp.int32)[None, :])
+        k, v = k.swapaxes(1, 2), v.swapaxes(1, 2)      # (B, Hkv, 1, Dh)
         if window:
             ck = _write_ring(cache["k"], k, pos, window)
             cv = _write_ring(cache["v"], v, pos, window)
         else:
-            ck = _write_full_cache(cache["k"], k, pos)
-            cv = _write_full_cache(cache["v"], v, pos)
+            ck = _write_full_cache(cache["k"], k, pos, _KV_SEQ_AXIS)
+            cv = _write_full_cache(cache["v"], v, pos, _KV_SEQ_AXIS)
         if cfg.use_pallas_kernels:
             # Pallas flash-decode: position mask → per-batch valid length.
             # Full cache: slots 0..pos hold tokens 0..pos.  Ring cache
@@ -174,7 +182,7 @@ def apply_attn_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
             # permutation-invariant over KV, so a plain length mask is
             # exact for both layouts.
             from ..kernels import ops as kernel_ops
-            L = ck.shape[1]
+            L = ck.shape[_KV_SEQ_AXIS]
             lengths = jnp.broadcast_to(
                 jnp.minimum(jnp.asarray(pos, jnp.int32) + 1, L),
                 (q.shape[0],))
@@ -203,12 +211,13 @@ def apply_attn_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
                                      block_kv=cfg.attn_block_kv)
         if mode == "prefill":
             assert cache is not None
+            k, v = k.swapaxes(1, 2), v.swapaxes(1, 2)  # (B, Hkv, S, Dh)
             if window:
                 ck = _prefill_ring(cache["k"], k, window)
                 cv = _prefill_ring(cache["v"], v, window)
             else:
-                ck = _write_full_cache(cache["k"], k, 0)
-                cv = _write_full_cache(cache["v"], v, 0)
+                ck = _write_full_cache(cache["k"], k, 0, _KV_SEQ_AXIS)
+                cv = _write_full_cache(cache["v"], v, 0, _KV_SEQ_AXIS)
             cache = dict(cache, k=ck, v=cv)
 
     out = jnp.einsum("bshk,hkd->bsd", attn, params["attn"]["wo"])

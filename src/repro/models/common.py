@@ -352,7 +352,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
                      seq_shard: bool = False):
     """Single-token attention against a (possibly ring-buffered) KV cache.
 
-    q: (B, 1, H, D); caches: (B, S_cache, Hkv, D); pos: scalar count of
+    q: (B, 1, H, D); caches: (B, Hkv, S_cache, D); pos: scalar count of
     tokens already written (the new token's kv must already be in the
     cache).  For windowed layers the cache is a ring buffer of length
     ``window`` and every slot < min(pos+1, window) is valid.
@@ -360,14 +360,14 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
     (flash-decode partials; see shard_decode_scores).
     """
     B, _, H, D = q.shape
-    S, Hkv = k_cache.shape[1], k_cache.shape[2]
+    Hkv, S = k_cache.shape[1], k_cache.shape[2]
     rep = H // Hkv
     scale = 1.0 / math.sqrt(D)
     # grouped GQA einsum: contract directly against the Hkv-cache instead
     # of materializing a rep×-replicated copy (the cache is the dominant
     # HBM traffic at long context — §Perf iteration 2)
     qg = q.reshape(B, 1, Hkv, rep, D)
-    s = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k_cache,
+    s = jnp.einsum("bqhrd,bhkd->bhrqk", qg, k_cache,
                    preferred_element_type=jnp.float32) * scale
     s = s.reshape(B, H, 1, S)
     if seq_shard:
@@ -379,7 +379,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
     if seq_shard:
         p = shard_decode_scores(p)
     pg = p.reshape(B, Hkv, rep, 1, S)
-    out = jnp.einsum("bhrqk,bkhd->bqhrd", pg.astype(v_cache.dtype), v_cache)
+    out = jnp.einsum("bhrqk,bhkd->bqhrd", pg.astype(v_cache.dtype), v_cache)
     return out.reshape(B, 1, H, D).astype(q.dtype)
 
 

@@ -1,10 +1,11 @@
 """Autoregressive LM serving engine: ``models/lm.py`` behind the plane.
 
 This module is the bridge from "Packrat for one-shot inference" to
-"Packrat for LLM serving": it wires a gemma3_1b-style scaled-down
-decoder (``lm-tiny``) into :class:`~repro.serving.plane.RealPlane`
-behind the existing ``make_runner(t, b)`` factory contract, split into
-the two phases of LLM inference with opposite resource profiles:
+"Packrat for LLM serving": it wires a decoder — the published
+``gemma3-1b``, or its smoke-size reduction ``lm-tiny`` — into
+:class:`~repro.serving.plane.RealPlane` behind the existing
+``make_runner(t, b)`` factory contract, split into the two phases of LLM
+inference with opposite resource profiles:
 
 * **prefill** (compute-bound) — one full-prompt forward through the
   Pallas ``flash_attention`` kernel, building the KV cache.  Runner
@@ -23,13 +24,15 @@ first-touch cost and the controller's plan-apply hook can warm cells
 ahead of traffic.
 
 The kernels are reached through ``cfg.use_pallas_kernels`` (see
-``models/blocks.py``): ``lm-tiny`` sets it, so serving runners, the
-differential tests, and the kernel oracles all execute one code path.
+``models/blocks.py``): every serving config sets it, so serving runners,
+the differential tests, and the kernel oracles all execute one code
+path.
 """
 
 from __future__ import annotations
 
 import functools
+import threading
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -39,7 +42,7 @@ from ..configs.gemma3_1b import GEMMA3_1B
 from ..core.knapsack import next_power_of_two
 from .lm import Model, build_model
 
-LM_MODELS = ("lm-tiny",)
+LM_MODELS = ("lm-tiny", "gemma3-1b")
 
 PHASE_PREFILL = "prefill"
 PHASE_DECODE = "decode"
@@ -59,13 +62,34 @@ def lm_tiny_config():
         name="lm-tiny", dtype="float32", use_pallas_kernels=True)
 
 
+def gemma3_1b_config():
+    """gemma3-1b at its published widths and full depth (26 layers,
+    d_model 1152, 4 q heads / 1 kv head of dim 256, sliding window 512,
+    vocab 262144), bf16, routed through the Pallas kernels: about 1 B
+    parameters, 2 GB, which one 16 GB v5e chip holds whole."""
+    return GEMMA3_1B.with_overrides(use_pallas_kernels=True)
+
+
+# name → (config builder, LmEngine defaults).  gemma3-1b's max_seq of
+# 1024 exceeds its 512-token window, so decode runs against both the
+# 512-slot ring caches of the local layers and the full caches of the
+# global ones.
+_LM_REGISTRY = {
+    "lm-tiny": (lm_tiny_config, {}),
+    "gemma3-1b": (gemma3_1b_config,
+                  {"max_seq": 1024, "default_seq_bucket": 128}),
+}
+
+
 class LmEngine:
     """KV-cache pool + pow2-bucketed jitted runners for one decoder.
 
     ``factory()`` returns the plane-facing runner factory (marked
     ``phase_aware``: the plane passes the worker pool's phase as a third
     argument).  ``prefill``/``decode_step`` expose the same jitted
-    callables functionally for the differential tests.
+    callables functionally for the differential tests; ``jit_prefill``
+    and ``jit_decode`` are those programs themselves, for lowering and
+    inspection.
     """
 
     def __init__(self, cfg=None, *, seed: int = 0, max_seq: int = 64,
@@ -75,7 +99,8 @@ class LmEngine:
             raise ValueError("LmEngine serves through the Pallas kernels; "
                              "cfg.use_pallas_kernels must be set")
         self.model: Model = build_model(self.cfg)
-        self.params = self.model.init(jax.random.PRNGKey(seed))
+        # one compiled init program, not one dispatch per weight
+        self.params = jax.jit(self.model.init)(jax.random.PRNGKey(seed))
         if max_seq < 2 or default_seq_bucket >= max_seq:
             raise ValueError(
                 f"need default_seq_bucket < max_seq, got "
@@ -97,10 +122,14 @@ class LmEngine:
         def _decode(params, cache, tokens, pos):
             return model.decode_step(params, cache, tokens, pos)
 
-        self._jit_prefill = _prefill
-        self._jit_decode = _decode
-        # ⟨b⟩-keyed resident decode state: (cache, python position)
+        self.jit_prefill = _prefill
+        self.jit_decode = _decode
+        # ⟨b⟩-keyed resident decode state: (cache, python position).
+        # A step donates its cell's cache, so two workers stepping one
+        # cell at once would read a deleted buffer: each cell steps under
+        # its own lock.
         self._resident: Dict[int, Tuple[object, int]] = {}
+        self._resident_locks: Dict[int, threading.Lock] = {}
         self._runners: Dict[Tuple[str, int, int], Callable[[], None]] = {}
 
     # ------------------------------------------------------------------ #
@@ -108,11 +137,11 @@ class LmEngine:
     # ------------------------------------------------------------------ #
     def prefill(self, tokens):
         """(logits_last (B,1,V), cache) for a (B, S) prompt batch."""
-        return self._jit_prefill(self.params, jnp.asarray(tokens, jnp.int32))
+        return self.jit_prefill(self.params, jnp.asarray(tokens, jnp.int32))
 
     def decode_step(self, cache, tokens, pos):
         """One decode step; donates ``cache`` (do not reuse the input)."""
-        return self._jit_decode(self.params, cache,
+        return self.jit_decode(self.params, cache,
                                 jnp.asarray(tokens, jnp.int32),
                                 jnp.asarray(pos, jnp.int32))
 
@@ -142,7 +171,7 @@ class LmEngine:
         run = self._runners.get(key)
         if run is None:
             tokens = self._sample_tokens(b, s)
-            fn, params = self._jit_prefill, self.params
+            fn, params = self.jit_prefill, self.params
             jax.block_until_ready(fn(params, tokens))   # compile here
 
             def run() -> None:
@@ -163,15 +192,17 @@ class LmEngine:
             s0 = self.default_seq_bucket
             _, cache = self.prefill(self._sample_tokens(b, s0))
             self._resident[b] = (cache, s0)
+            lock = self._resident_locks.setdefault(b, threading.Lock())
             engine = self
 
             def step() -> None:
-                cache, pos = engine._resident[b]
-                tokens = jnp.zeros((b, 1), jnp.int32)
-                logits, cache = engine.decode_step(cache, tokens, pos)
-                logits.block_until_ready()
-                nxt = s0 + (pos - s0 + 1) % (engine.max_seq - s0)
-                engine._resident[b] = (cache, nxt)
+                with lock:
+                    cache, pos = engine._resident[b]
+                    tokens = jnp.zeros((b, 1), jnp.int32)
+                    logits, cache = engine.decode_step(cache, tokens, pos)
+                    logits.block_until_ready()
+                    nxt = s0 + (pos - s0 + 1) % (engine.max_seq - s0)
+                    engine._resident[b] = (cache, nxt)
 
             step()                                       # compile here
 
@@ -202,78 +233,15 @@ class LmEngine:
 
 
 def make_lm_engine(name: str = "lm-tiny", *, seed: int = 0, **kw) -> LmEngine:
-    """Engine for one registered LM serving model."""
+    """Engine for one registered LM serving model; ``kw`` overrides the
+    model's registered engine defaults."""
     if name not in LM_MODELS:
         raise ValueError(f"unknown LM serving model {name!r}; "
                          f"choose from {sorted(LM_MODELS)}")
-    return LmEngine(lm_tiny_config(), seed=seed, **kw)
-
-
-# --------------------------------------------------------------------- #
-# fidelity ladder: per-rung reduced decoders
-# --------------------------------------------------------------------- #
-def lm_tiny_rung_configs(n_rungs: int = 3):
-    """Rung configs for the ``lm-tiny`` fidelity ladder (rung 0 first).
-
-    Rung 0 is :func:`lm_tiny_config` verbatim — ladder-off serving is
-    unchanged.  Higher rungs shrink width and FFN via the same
-    ``GEMMA3_1B.reduced`` machinery: genuinely cheaper Pallas-kernel
-    decoders, not discounted latency tables.
-    """
-    reductions = [
-        dict(n_repeats=1, d_model=32, n_heads=2, d_ff=64, vocab_size=256),
-        dict(n_repeats=1, d_model=16, n_heads=2, d_ff=32, vocab_size=256),
-        dict(n_repeats=1, d_model=8, n_heads=1, d_ff=16, vocab_size=256),
-    ]
-    if not (1 <= n_rungs <= len(reductions)):
-        raise ValueError(f"n_rungs must be in [1, {len(reductions)}], "
-                         f"got {n_rungs}")
-    cfgs = [lm_tiny_config()]
-    for r, red in enumerate(reductions[1:n_rungs], start=1):
-        cfgs.append(GEMMA3_1B.reduced(
-            name=f"lm-tiny:r{r}", dtype="float32",
-            use_pallas_kernels=True, **red))
-    return cfgs
-
-
-def make_fidelity_lm_factory(name: str = "lm-tiny", *, seed: int = 0,
-                             n_rungs: int = 3, seq_bucket: int = 16, **kw):
-    """Fidelity- and phase-aware runner factory for an LM ladder.
-
-    Builds one :class:`LmEngine` per rung (rung 0 identical to
-    :func:`make_lm_engine`'s engine, so ladder-off execution is
-    unchanged); higher rungs pair their narrower decoder with a halved
-    seq bucket — degraded prompts are truncated harder, which is where
-    the prefill savings come from.  Returns ``make(t, b, phase, *,
-    fidelity=0)`` carrying both the ``phase_aware`` and
-    ``fidelity_aware`` markers RealPlane keys its runner cache on.
-    """
-    if name not in LM_MODELS:
-        raise ValueError(f"unknown LM serving model {name!r}; "
-                         f"choose from {sorted(LM_MODELS)}")
-    engines = []
-    buckets = []
-    for rung, cfg in enumerate(lm_tiny_rung_configs(n_rungs)):
-        s = max(2, seq_bucket >> rung)
-        engines.append(LmEngine(cfg, seed=seed,
-                                default_seq_bucket=s, **kw))
-        buckets.append(s)
-    factories = [eng.factory(seq_bucket=s)
-                 for eng, s in zip(engines, buckets)]
-
-    def make(t: int, b: int, phase: str = PHASE_DECODE, *,
-             fidelity: int = 0) -> Callable[[], None]:
-        if not (0 <= fidelity < len(factories)):
-            raise ValueError(f"fidelity rung {fidelity} out of range "
-                             f"[0, {len(factories)})")
-        return factories[fidelity](t, b, phase)
-
-    make.phase_aware = True
-    make.fidelity_aware = True
-    make.engines = tuple(engines)
-    return make
+    make_cfg, defaults = _LM_REGISTRY[name]
+    return LmEngine(make_cfg(), seed=seed, **{**defaults, **kw})
 
 
 __all__ = ["LM_MODELS", "LmEngine", "PHASES", "PHASE_DECODE",
-           "PHASE_PREFILL", "lm_tiny_config", "lm_tiny_rung_configs",
-           "make_fidelity_lm_factory", "make_lm_engine"]
+           "PHASE_PREFILL", "gemma3_1b_config", "lm_tiny_config",
+           "make_lm_engine"]

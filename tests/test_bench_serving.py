@@ -373,3 +373,25 @@ def test_mm_slo_ms_per_model_feasible_batch():
     assert set(feas) == {"resnet50", "bert"}
     for name, sub in feas.items():
         assert sub is not None and sub["latency_ms"] <= 500.0
+
+
+# --------------------------------------------------------------------- #
+# persistent compilation cache placement
+# --------------------------------------------------------------------- #
+def test_compile_cache_defers_to_env_else_fixed_checkout_path(monkeypatch):
+    import jax
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(compile_cache.CACHE_ENV, "/elsewhere/cache")
+        assert compile_cache.configure_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None   # untouched
+        monkeypatch.delenv(compile_cache.CACHE_ENV)
+        got = compile_cache.configure_compile_cache()
+        root = compile_cache.DEFAULT_CACHE_DIR.parent
+        assert got == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+        assert (root / "src" / "repro").is_dir()     # inside the checkout
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -165,9 +165,8 @@ def test_compressed_psum_close_to_exact():
     def f(xs):
         return compressed_psum(xs, "pod")
 
-    from repro.distributed import shard_map
-    got = jax.jit(shard_map(f, mesh=mesh, in_specs=P("pod"),
-                            out_specs=P("pod")))(x)
+    got = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                                out_specs=P("pod")))(x)
     want = jnp.broadcast_to(x.sum(0, keepdims=True), x.shape)
     rms_rel = float(jnp.sqrt(jnp.mean((got - want) ** 2))
                     / jnp.sqrt(jnp.mean(want ** 2)))

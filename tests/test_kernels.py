@@ -83,8 +83,8 @@ def test_flash_attention_matches_model_blocked_path():
 def test_decode_attention(B, S, H, Hkv, D, dtype):
     keys = jax.random.split(jax.random.PRNGKey(3), 4)
     q = jax.random.normal(keys[0], (B, 1, H, D), dtype)
-    kc = jax.random.normal(keys[1], (B, S, Hkv, D), dtype)
-    vc = jax.random.normal(keys[2], (B, S, Hkv, D), dtype)
+    kc = jax.random.normal(keys[1], (B, Hkv, S, D), dtype)
+    vc = jax.random.normal(keys[2], (B, Hkv, S, D), dtype)
     lengths = jax.random.randint(keys[3], (B,), 1, S + 1)
     got = ops.decode_attention(q, kc, vc, lengths, block_kv=32)
     want = ref.decode_attention_ref(q, kc, vc, lengths)
@@ -97,8 +97,8 @@ def test_decode_attention_matches_model_decode():
     B, S, H, Hkv, D = 2, 64, 4, 2, 32
     keys = jax.random.split(jax.random.PRNGKey(4), 3)
     q = jax.random.normal(keys[0], (B, 1, H, D))
-    kc = jax.random.normal(keys[1], (B, S, Hkv, D))
-    vc = jax.random.normal(keys[2], (B, S, Hkv, D))
+    kc = jax.random.normal(keys[1], (B, Hkv, S, D))
+    vc = jax.random.normal(keys[2], (B, Hkv, S, D))
     pos = 37
     got = ops.decode_attention(q, kc, vc, jnp.full((B,), pos + 1), block_kv=32)
     want = model_decode(q, kc, vc, pos)
@@ -176,25 +176,39 @@ def test_rglru_assoc_scan_matches_sequential():
     _assert_close(h_assoc, h_seq, jnp.float32)
 
 # --------------------------------------------------------------------- #
+# interpret mode only on the CPU test platform
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_kernels_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="'gpu' backend"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret
+
+
+# --------------------------------------------------------------------- #
 # decode attention: argument validation (PR 9 satellite)
 # --------------------------------------------------------------------- #
 def test_decode_attention_validates_arguments():
     B, S, H, Hkv, D = 2, 64, 4, 2, 32
     keys = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(keys[0], (B, 1, H, D))
-    kc = jax.random.normal(keys[1], (B, S, Hkv, D))
-    vc = jax.random.normal(keys[2], (B, S, Hkv, D))
+    kc = jax.random.normal(keys[1], (B, Hkv, S, D))
+    vc = jax.random.normal(keys[2], (B, Hkv, S, D))
     lengths = jnp.full((B,), S)
     cases = [
         (dict(q=q[:, 0]), "must be \\(B, 1, H, D\\)"),            # 3-D q
         (dict(q=jnp.repeat(q, 2, axis=1)), "must be \\(B, 1, H, D\\)"),
-        (dict(vc=vc[:, : S // 2]), "shapes differ"),
+        (dict(vc=vc[:, :, : S // 2]), "shapes differ"),
         (dict(q=q[:1]), "batch mismatch"),
         (dict(q=q[..., : D // 2]), "head dim mismatch"),
-        (dict(kc=kc[:, :, :1], vc=vc[:, :, :1]),                  # Hkv=1 ok;
+        (dict(kc=kc[:, :1], vc=vc[:, :1]),                        # Hkv=1 ok;
          None),                                                   # MQA valid
-        (dict(kc=kc[:, :, :, :].repeat(3, axis=2),
-              vc=vc[:, :, :, :].repeat(3, axis=2)), "multiple"),  # Hkv=6 > H? no, 6 not divisor of 4
+        (dict(kc=kc.repeat(3, axis=1),
+              vc=vc.repeat(3, axis=1)), "multiple"),  # Hkv=6 does not divide H=4
         (dict(q=q.astype(jnp.bfloat16)), "dtype mismatch"),
         (dict(lengths=jnp.full((B, 1), S)), "lengths must be"),
     ]
@@ -215,7 +229,7 @@ def test_decode_attention_rejects_unpadded_cache_length():
     B, S, H, Hkv, D = 1, 48, 2, 1, 16
     keys = jax.random.split(jax.random.PRNGKey(8), 3)
     q = jax.random.normal(keys[0], (B, 1, H, D))
-    kc = jax.random.normal(keys[1], (B, S, Hkv, D))
-    vc = jax.random.normal(keys[2], (B, S, Hkv, D))
+    kc = jax.random.normal(keys[1], (B, Hkv, S, D))
+    vc = jax.random.normal(keys[2], (B, Hkv, S, D))
     with pytest.raises(ValueError, match="multiple of\\s+block_kv"):
         raw(q, kc, vc, jnp.full((B,), S), block_kv=32, interpret=True)

@@ -131,6 +131,25 @@ def test_make_lm_engine_registry():
         make_lm_engine("no-such-model")
 
 
+def test_gemma3_1b_engine_at_published_widths(monkeypatch):
+    """gemma3-1b is served whole: published widths, all 26 layers, bf16,
+    through the kernels, with a max_seq past its 512-token window (the
+    engine itself is 2 GB, so its constructor is stubbed here)."""
+    from repro.models import serve_lm
+    monkeypatch.setattr(serve_lm, "LmEngine",
+                        lambda cfg, **kw: (cfg, kw))
+    cfg, kw = make_lm_engine("gemma3-1b", seed=3)
+    assert "gemma3-1b" in LM_MODELS
+    assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
+            cfg.resolved_head_dim, cfg.vocab_size) == \
+        (26, 1152, 4, 1, 256, 262_144)
+    assert cfg.dtype == "bfloat16" and cfg.use_pallas_kernels
+    assert kw["seed"] == 3
+    assert kw["max_seq"] >= 1024 > cfg.sliding_window == 512
+    # caller overrides win over the registered defaults
+    assert make_lm_engine("gemma3-1b", max_seq=2048)[1]["max_seq"] == 2048
+
+
 # --------------------------------------------------------------------- #
 # RealPlane: phase-keyed runner cache, LRU bound, warm-up
 # --------------------------------------------------------------------- #
