@@ -6,6 +6,7 @@
 * :mod:`repro.core.reconfig` — active-passive zero-downtime scaling.
 * :mod:`repro.core.roofline` — TPU roofline terms behind the analytic profile.
 * :mod:`repro.core.interference` — multi-instance contention models.
+* :mod:`repro.core.spans` — the served path's spans in the profiler's trace.
 """
 
 from .estimator import (ArrivalRateSignal, BatchSizeEstimator,

@@ -21,7 +21,9 @@ continuous-batching server holds for its in-flight sequences.  Every
 jitted callable is compiled inside the factory (outside the timed
 path), so :class:`RealPlane`'s ``compile_ms`` accounting captures the
 first-touch cost and the controller's plan-apply hook can warm cells
-ahead of traffic.
+ahead of traffic.  A runner call's wait for its decode cell's lock, its
+enqueue and its wait for the device are spans in the profiler's trace
+(``packrat.lock``, ``packrat.enqueue``, ``packrat.device_wait``).
 
 The kernels are reached through ``cfg.use_pallas_kernels`` (see
 ``models/blocks.py``): every serving config sets it, so serving runners,
@@ -39,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.gemma3_1b import GEMMA3_1B
+from ..core import spans
 from ..core.knapsack import next_power_of_two
 from .lm import Model, build_model
 
@@ -175,7 +178,12 @@ class LmEngine:
             jax.block_until_ready(fn(params, tokens))   # compile here
 
             def run() -> None:
-                jax.block_until_ready(fn(params, tokens))
+                with spans.span("packrat.enqueue", phase=PHASE_PREFILL,
+                                b=b):
+                    out = fn(params, tokens)
+                with spans.span("packrat.device_wait", phase=PHASE_PREFILL,
+                                b=b):
+                    jax.block_until_ready(out)
 
             self._runners[key] = run
         return run
@@ -196,13 +204,22 @@ class LmEngine:
             engine = self
 
             def step() -> None:
-                with lock:
+                with spans.span("packrat.lock", b=b):
+                    lock.acquire()
+                try:
                     cache, pos = engine._resident[b]
-                    tokens = jnp.zeros((b, 1), jnp.int32)
-                    logits, cache = engine.decode_step(cache, tokens, pos)
-                    logits.block_until_ready()
+                    with spans.span("packrat.enqueue", phase=PHASE_DECODE,
+                                    b=b):
+                        tokens = jnp.zeros((b, 1), jnp.int32)
+                        logits, cache = engine.decode_step(cache, tokens,
+                                                           pos)
+                    with spans.span("packrat.device_wait",
+                                    phase=PHASE_DECODE, b=b):
+                        logits.block_until_ready()
                     nxt = s0 + (pos - s0 + 1) % (engine.max_seq - s0)
                     engine._resident[b] = (cache, nxt)
+                finally:
+                    lock.release()
 
             step()                                       # compile here
 

@@ -30,6 +30,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List, Optional, Tuple
 
+from ..core import spans
 from ..core.estimator import BatchSizeEstimator, EstimatorConfig
 from ..core.knapsack import PackratConfig, PackratOptimizer
 from ..core.profiler import ProfileCalibrator
@@ -441,7 +442,12 @@ class PackratServer(ModelTenant):
         self.loop.schedule(self.ccfg.tick_interval, self._tick)
 
     def _tick(self) -> None:
-        self.tick()
+        n_plans = len(self.reconfig_log)
+        with spans.span("packrat.control", pool=self.model_id) as span:
+            self.tick()
+            if span is not None:
+                span.set_metadata(
+                    replanned=int(len(self.reconfig_log) > n_plans))
         self._schedule_tick()
 
     # ------------------------------------------------------------------ #

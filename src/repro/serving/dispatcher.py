@@ -28,6 +28,7 @@ import collections
 import dataclasses
 from typing import Callable, Deque, Dict, List, Optional, Sequence
 
+from ..core import spans
 from ..core.knapsack import PackratConfig
 from .instance import WorkerInstance
 from .plane import ExecutionPlane, as_plane
@@ -237,7 +238,23 @@ class Dispatcher:
         plane delivers the completion callback (virtual-time event or
         wall-clock thread completion) and the dispatcher schedules a
         watchdog that re-dispatches stragglers and retires completed ids
-        once no copy can still deliver them."""
+        once no copy can still deliver them.  The whole of it is the
+        batch's ``packrat.dispatch`` span, with the queue wait of its
+        requests (for a decode step, since its re-enqueue)."""
+        batch = self.plane.next_batch_id()
+        span = spans.NOOP
+        if spans.on():
+            waits = [self.loop.now - r.arrival for r in sub]
+            span = spans.span(
+                "packrat.dispatch", phase=self.model_id, batch=batch,
+                n=len(sub), rid=sub[0].id,
+                wait_sum_us=round(sum(waits) * 1e6),
+                wait_max_us=round(max(waits) * 1e6))
+        with span:
+            self._run_batch(worker, sub, threads, redispatch, batch)
+
+    def _run_batch(self, worker: WorkerInstance, sub: List[Request],
+                   threads: int, redispatch: int, batch: int) -> None:
         n_live = len(self._live())
         if self.peer_live is not None:
             n_live += self.peer_live()
@@ -297,7 +314,8 @@ class Dispatcher:
         for r in sub:
             self._inflight_ids[r.id] = self._inflight_ids.get(r.id, 0) + 1
         expected = self.plane.execute_batch(
-            worker, len(sub), n_live_instances=n_live, on_complete=complete)
+            worker, len(sub), n_live_instances=n_live, on_complete=complete,
+            batch=batch)
         deadline = self.loop.now + expected * self.dcfg.straggler_factor
         for r in sub:
             self._retire_at[r.id] = max(self._retire_at.get(r.id, 0.0),

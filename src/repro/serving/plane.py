@@ -20,7 +20,8 @@ delivery** — so :class:`~repro.serving.dispatcher.Dispatcher`,
   intra-op pool per call, so the budget bounds co-running claims rather
   than pinning threads), timers fire on the wall clock, and completions
   are delivered back on the driving thread so controller state never
-  needs locks.
+  needs locks.  Its unit-gate waits, runner calls, deliveries and timers
+  are spans in the profiler's trace (:mod:`repro.core.spans`).
 
 Both planes expose the :class:`~repro.serving.simulator.EventLoop`
 scheduling interface (``now``/``at``/``schedule``/``run_until``), so
@@ -44,6 +45,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
+from ..core import spans
 from ..core.knapsack import next_power_of_two
 from ..core.profiler import MeasuredProfiler, Profile, ProfileSpec
 from .instance import WorkerInstance
@@ -70,6 +72,15 @@ class ExecutionPlane:
     name = "abstract"
     fidelity = 0            # current fidelity rung (0 = full fidelity)
 
+    def __init__(self) -> None:
+        self._batch_ids = itertools.count(1)
+
+    def next_batch_id(self) -> int:
+        """The plane-wide sequence number of a new batch: the ``batch``
+        argument that links the dispatcher's span of a batch to the
+        plane's."""
+        return next(self._batch_ids)
+
     # ------------------------------------------------------------------ #
     # time (EventLoop-compatible)
     # ------------------------------------------------------------------ #
@@ -91,7 +102,10 @@ class ExecutionPlane:
     # ------------------------------------------------------------------ #
     def execute_batch(self, worker: WorkerInstance, n_items: int, *,
                       n_live_instances: int = 1, total_units: int = 0,
-                      on_complete: Callable[[float], None]) -> float:
+                      on_complete: Callable[[float], None],
+                      batch: int = 0) -> float:
+        """``batch`` is the batch's :meth:`next_batch_id` (0 when the
+        caller numbers none), carried by the plane's spans of it."""
         raise NotImplementedError
 
     def release_worker(self, worker: WorkerInstance) -> None:
@@ -151,6 +165,7 @@ class SimulatedPlane(ExecutionPlane):
     name = "sim"
 
     def __init__(self, loop: Optional[EventLoop] = None) -> None:
+        super().__init__()
         self.loop = loop if loop is not None else EventLoop()
 
     @property
@@ -171,7 +186,8 @@ class SimulatedPlane(ExecutionPlane):
 
     def execute_batch(self, worker: WorkerInstance, n_items: int, *,
                       n_live_instances: int = 1, total_units: int = 0,
-                      on_complete: Callable[[float], None]) -> float:
+                      on_complete: Callable[[float], None],
+                      batch: int = 0) -> float:
         now = self.loop.now
         busy_before = worker.busy_until
         done_t = worker.process(n_items, now,
@@ -206,6 +222,7 @@ class RealPlane(ExecutionPlane):
     def __init__(self, make_runner: RunnerFactory, total_units: int, *,
                  clock: Callable[[], float] = time.perf_counter,
                  max_runners: int = 32) -> None:
+        super().__init__()
         if total_units < 1:
             raise ValueError(f"total_units must be >= 1, got {total_units}")
         if max_runners < 1:
@@ -225,7 +242,9 @@ class RealPlane(ExecutionPlane):
         self._epoch: Optional[float] = None
         self._timers: List[Tuple[float, int, Callable[[], None]]] = []
         self._seq = itertools.count()
-        self._completions: "queue.Queue[Callable[[], None]]" = queue.Queue()
+        # (batch id, completion callback) posted by the worker threads
+        self._completions: "queue.Queue[Tuple[int, Callable[[], None]]]" \
+            = queue.Queue()
         # LRU-bounded compiled-runner cache: long sweeps over batch sizes
         # (or phase × seq-bucket cells) must not accumulate executables
         # unboundedly.  Evicting an in-flight runner is safe — the
@@ -241,9 +260,8 @@ class RealPlane(ExecutionPlane):
         self._executors: Dict[int, ThreadPoolExecutor] = {}
         self._units_cv = threading.Condition()
         self._units_free = total_units
-        self.inflight = 0
-        self.batches_executed = 0
         self._closed = False
+        spans.watch_collector()
 
     # ------------------------------------------------------------------ #
     # time
@@ -265,13 +283,27 @@ class RealPlane(ExecutionPlane):
         heapq.heappush(self._timers, (max(time, self.now),
                                       next(self._seq), fn))
 
+    @staticmethod
+    def _deliver(item: Tuple[int, Callable[[], None]]) -> None:
+        batch, fn = item
+        with spans.span("packrat.deliver", batch=batch):
+            fn()
+
+    def _fire(self, due: float, fn: Callable[[], None]) -> None:
+        if not spans.on():
+            fn()
+            return
+        with spans.span("packrat.timer",
+                        late_us=round((self.now - due) * 1e6)):
+            fn()
+
     def _drain_completions(self) -> None:
         while True:
             try:
-                fn = self._completions.get_nowait()
+                item = self._completions.get_nowait()
             except queue.Empty:
                 return
-            fn()
+            self._deliver(item)
 
     def run_until(self, t_end: float) -> None:
         """Drive the reactor until wall time ``t_end`` (seconds since
@@ -285,8 +317,8 @@ class RealPlane(ExecutionPlane):
             self._drain_completions()
             # fire every timer due by min(now, t_end)
             while self._timers and self._timers[0][0] <= min(self.now, t_end):
-                _, _, fn = heapq.heappop(self._timers)
-                fn()
+                due, _, fn = heapq.heappop(self._timers)
+                self._fire(due, fn)
                 self._drain_completions()
             now = self.now
             if now >= t_end:
@@ -294,10 +326,10 @@ class RealPlane(ExecutionPlane):
             next_t = self._timers[0][0] if self._timers else t_end
             timeout = max(0.0, min(next_t, t_end) - now)
             try:
-                fn = self._completions.get(timeout=min(timeout, 0.050))
+                item = self._completions.get(timeout=min(timeout, 0.050))
             except queue.Empty:
                 continue
-            fn()
+            self._deliver(item)
             self._drain_completions()
 
     # ------------------------------------------------------------------ #
@@ -389,7 +421,8 @@ class RealPlane(ExecutionPlane):
 
     def execute_batch(self, worker: WorkerInstance, n_items: int, *,
                       n_live_instances: int = 1, total_units: int = 0,
-                      on_complete: Callable[[float], None]) -> float:
+                      on_complete: Callable[[float], None],
+                      batch: int = 0) -> float:
         if self._closed:
             raise RuntimeError("plane is closed")
         self._start()
@@ -412,26 +445,28 @@ class RealPlane(ExecutionPlane):
                           phase=self._worker_phase(worker),
                           fidelity=self.fidelity)
         claim = min(worker.threads, self.total_units)
-        self.inflight += 1
+        b = next_power_of_two(n_items)
 
         def job() -> None:
-            self._acquire_units(claim)
+            with spans.span("packrat.gate", batch=batch, units=claim):
+                self._acquire_units(claim)
             try:
-                t0 = self._clock()
-                run()
-                observed = self._clock() - t0
+                with spans.span("packrat.run", batch=batch,
+                                phase=worker.model_id, b=b, n=n_items,
+                                worker=worker.id):
+                    t0 = self._clock()
+                    run()
+                    observed = self._clock() - t0
             finally:
                 self._release_units(claim)
-            self._completions.put(
-                lambda: self._complete(worker, observed, on_complete))
+            self._completions.put((batch, lambda: self._complete(
+                worker, observed, on_complete)))
 
         self._executor_for(worker).submit(job)
         return expected_done
 
     def _complete(self, worker: WorkerInstance, observed: float,
                   on_complete: Callable[[float], None]) -> None:
-        self.inflight -= 1
-        self.batches_executed += 1
         worker.finish_batch(self.now, observed)
         on_complete(observed)
 
