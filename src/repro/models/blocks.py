@@ -13,6 +13,15 @@ Every block follows the same functional contract:
 
 ``mode`` ∈ {"train", "prefill", "decode"}; decode consumes/produces the
 cache and processes exactly one token.
+
+A scanned decode step hands each block the stacked cache of every
+repeat (leaves ``(R, ...)``) and the repeat's index ``layer``.  The block
+reads its layer's slice, with this step's position written into the
+slice, and returns only what the step writes: the new position of each
+K/V or latent cache, or a recurrent layer's whole new state.  After the
+scan :func:`write_decode_step` writes every layer's entries into the
+stack at once, in place (the stacked cache is never copied or rebuilt);
+cross-attention memory is read-only and kept.
 """
 
 from __future__ import annotations
@@ -137,10 +146,22 @@ def _write_full_cache(cache_arr, new, pos, axis: int = 1):
         cache_arr.dtype), pos, axis=axis)
 
 
+def _ring_slot(pos, window):
+    """Slot of token pos in a ring cache of `window` slots."""
+    return jnp.asarray(pos) % window
+
+
 def _write_ring(cache_arr, new, pos, window):
     """Write one head-major token at slot pos % window (decode)."""
-    slot = jnp.asarray(pos) % window
-    return _write_full_cache(cache_arr, new, slot, _KV_SEQ_AXIS)
+    return _write_full_cache(cache_arr, new, _ring_slot(pos, window),
+                             _KV_SEQ_AXIS)
+
+
+def _layer_slice(cache_arr, layer):
+    """A stacked cache leaf's slice at ``layer`` (the leaf if None)."""
+    if layer is None:
+        return cache_arr
+    return jax.lax.dynamic_index_in_dim(cache_arr, layer, keepdims=False)
 
 
 def _prefill_ring(cache_arr, k_seq, window):
@@ -156,8 +177,10 @@ def _prefill_ring(cache_arr, k_seq, window):
 
 def apply_attn_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
                      positions=None, pos=None, cache: Optional[Dict] = None,
-                     memory=None):
-    """x: (B, S, d). decode: S == 1 and `pos` is the scalar write position."""
+                     memory=None, layer=None):
+    """x: (B, S, d). decode: S == 1 and `pos` is the scalar write position;
+    with `layer` (scanned decode) the cache is stacked and the block
+    returns this step's k/v instead of the cache."""
     causal = kind != ENC
     window = cfg.sliding_window if kind == LOCAL_ATTN else 0
     res = x
@@ -168,12 +191,14 @@ def apply_attn_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
         q, k, v = _qkv(params["attn"], h, cfg, kind,
                        jnp.full((1,), pos, jnp.int32)[None, :])
         k, v = k.swapaxes(1, 2), v.swapaxes(1, 2)      # (B, Hkv, 1, Dh)
+        ck = _layer_slice(cache["k"], layer)
+        cv = _layer_slice(cache["v"], layer)
         if window:
-            ck = _write_ring(cache["k"], k, pos, window)
-            cv = _write_ring(cache["v"], v, pos, window)
+            ck = _write_ring(ck, k, pos, window)
+            cv = _write_ring(cv, v, pos, window)
         else:
-            ck = _write_full_cache(cache["k"], k, pos, _KV_SEQ_AXIS)
-            cv = _write_full_cache(cache["v"], v, pos, _KV_SEQ_AXIS)
+            ck = _write_full_cache(ck, k, pos, _KV_SEQ_AXIS)
+            cv = _write_full_cache(cv, v, pos, _KV_SEQ_AXIS)
         if cfg.use_pallas_kernels:
             # Pallas flash-decode: position mask → per-batch valid length.
             # Full cache: slots 0..pos hold tokens 0..pos.  Ring cache
@@ -193,7 +218,8 @@ def apply_attn_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
             attn = decode_attention(
                 q, ck, cv, pos, window=window,
                 seq_shard=cfg.decode_seq_shard and not window)
-        cache = dict(cache, k=ck, v=cv)
+        new_cache = (dict(k=k, v=v) if layer is not None
+                     else dict(cache, k=ck, v=cv))
     else:
         q, k, v = _qkv(params["attn"], h, cfg, kind, positions)
         if cfg.seq_sharding and cfg.sp_gather_heads:
@@ -232,7 +258,8 @@ def apply_attn_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
         cp = params["cross"]
         q = jnp.einsum("bsd,dhk->bshk", h, cp["wq"])
         if mode == "decode":
-            mk, mv = cache["cross_k"], cache["cross_v"]
+            mk = _layer_slice(cache["cross_k"], layer)
+            mv = _layer_slice(cache["cross_v"], layer)
         else:
             mk = jnp.einsum("bsd,dhk->bshk", memory, cp["wk"])
             mv = jnp.einsum("bsd,dhk->bshk", memory, cp["wv"])
@@ -249,6 +276,8 @@ def apply_attn_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
     out = apply_mlp(params["mlp"], h, cfg.act, gated=_gated(cfg))
     if cfg.post_norms:
         out = apply_norm(params["post_mlp"], out, cfg.norm, cfg.norm_eps)
+    if mode == "decode":
+        cache = new_cache
     return res + out, cache
 
 
@@ -323,7 +352,8 @@ def _mla_kv_latent(params, h, cfg: ModelConfig, positions):
 
 
 def apply_mla_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
-                    positions=None, pos=None, cache: Optional[Dict] = None):
+                    positions=None, pos=None, cache: Optional[Dict] = None,
+                    layer=None):
     mla = cfg.mla
     scale = 1.0 / math.sqrt(mla.qk_nope_head_dim + mla.qk_rope_head_dim)
     res = x
@@ -334,9 +364,11 @@ def apply_mla_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
         posv = jnp.full((1,), pos, jnp.int32)[None, :]
         q_nope, q_rope = _mla_q(params, h, cfg, posv)          # (B,1,H,·)
         c_t, kr_t = _mla_kv_latent(params, h, cfg, posv)       # (B,1,·)
-        c_kv = _write_full_cache(cache["c_kv"], c_t, pos)
-        k_rope = _write_full_cache(cache["k_rope"], kr_t, pos)
-        cache = dict(cache, c_kv=c_kv, k_rope=k_rope)
+        c_kv = _write_full_cache(_layer_slice(cache["c_kv"], layer), c_t, pos)
+        k_rope = _write_full_cache(_layer_slice(cache["k_rope"], layer),
+                                   kr_t, pos)
+        cache = (dict(c_kv=c_t, k_rope=kr_t) if layer is not None
+                 else dict(cache, c_kv=c_kv, k_rope=k_rope))
         # absorbed attention: score in latent space, expand after combine
         q_lat = jnp.einsum("bqhn,lhn->bqhl", q_nope, params["wk_b"])
         s = (jnp.einsum("bqhl,bsl->bhqs", q_lat.astype(jnp.float32),
@@ -453,15 +485,40 @@ def init_block_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
 
 
 def apply_block(params, x, cfg: ModelConfig, kind: str, *, mode: str,
-                positions=None, pos=None, cache=None, memory=None):
+                positions=None, pos=None, cache=None, memory=None,
+                layer=None):
+    """``layer`` (scanned decode only): ``cache`` is stacked and the block
+    returns what the step writes, for :func:`write_decode_step`."""
     if kind in _ATTN_FAMILY:
         return apply_attn_block(params, x, cfg, kind, mode=mode,
                                 positions=positions, pos=pos, cache=cache,
-                                memory=memory)
+                                memory=memory, layer=layer)
     if kind in (MLA, MLA_MOE):
         return apply_mla_block(params, x, cfg, kind, mode=mode,
-                               positions=positions, pos=pos, cache=cache)
+                               positions=positions, pos=pos, cache=cache,
+                               layer=layer)
     if kind in (SSM, RGLRU):
+        if layer is not None:
+            cache = jax.tree.map(lambda a: _layer_slice(a, layer), cache)
         return apply_recurrent_block(params, x, cfg, kind, mode=mode,
                                      cache=cache)
     raise ValueError(f"unknown block kind {kind!r}")
+
+
+def write_decode_step(cfg: ModelConfig, kind: str, cache, written, pos):
+    """Write a scanned decode step into the stacked cache, every layer at
+    once: ``written`` is what :func:`apply_block` returned per layer,
+    stacked ``(R, ...)`` by the scan.  K/V and latent caches take one
+    position (a ring cache its slot) along the sequence axis, in place;
+    recurrent states are replaced whole; cross-attention memory is kept."""
+    if kind in (SSM, RGLRU):
+        return written
+    if kind in (MLA, MLA_MOE):
+        slot, axis = pos, 2                     # (R, B, S, ·)
+    else:
+        slot, axis = pos, 1 + _KV_SEQ_AXIS      # (R, B, Hkv, L, Dh)
+        if kind == LOCAL_ATTN and cfg.sliding_window:
+            slot = _ring_slot(pos, cfg.sliding_window)
+    return dict(cache, **{
+        name: _write_full_cache(cache[name], new, slot, axis)
+        for name, new in written.items()})

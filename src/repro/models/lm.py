@@ -7,6 +7,15 @@ they are unrolled (used by smoke tests and by the dry-run differencing
 cost analyzer).  Encoder-decoder configs (pattern ``(ENC, DEC)``) build
 two stacks that share ``n_repeats``.
 
+A scanned decode step leaves the stacked caches where they are: each
+layer reads its own slice by the layer index, the scan emits only the
+step's new entries, and one write per cache leaf puts every layer's new
+position into the stack in place after the scan
+(``blocks.write_decode_step``).  Feeding the caches through the scan as
+``xs`` and rebuilding them as ``ys`` would copy every layer's whole cache
+every step; carrying them in the scan's carry makes the TPU compiler
+copy a stack of head dim 64 into another layout and back.
+
 The public surface is :class:`Model` (build with :func:`build_model`):
 
     params                    = model.init(rng)
@@ -30,7 +39,8 @@ import jax.numpy as jnp
 
 from ..configs.base import (ATTN, DEC, ENC, LOCAL_ATTN, MLA, MLA_MOE, RGLRU,
                             SSM, ModelConfig, ShapeConfig)
-from .blocks import apply_block, init_block, init_block_cache
+from .blocks import (apply_block, init_block, init_block_cache,
+                     write_decode_step)
 from .common import apply_norm, embed_init, init_norm
 
 PyTree = Any
@@ -134,9 +144,9 @@ def _block_fn(cfg, kind, *, mode, positions, pos, memory):
     with the sequence-parallel activation constraint between blocks."""
     sp = cfg.seq_sharding and mode in ("train", "prefill")
 
-    def fn(p, h, c):
+    def fn(p, h, c, layer=None):
         h, c = apply_block(p, h, cfg, kind, mode=mode, positions=positions,
-                           pos=pos, cache=c, memory=memory)
+                           pos=pos, cache=c, memory=memory, layer=layer)
         if sp:
             from .common import shard_seq
             h = shard_seq(h)
@@ -180,6 +190,23 @@ def _run_pattern(params, x, cfg: ModelConfig, *, mode, positions=None,
                                memory=memory)
             new_caches.append(cs)
         return x, new_caches
+
+    if mode == "decode":
+        fns = [_block_fn(cfg, kind, mode=mode, positions=positions,
+                         pos=pos, memory=memory) for kind in kinds]
+
+        def step(h, xs):
+            layer_params, layer = xs
+            written = []
+            for j, fn in enumerate(fns):
+                h, w = fn(layer_params[j], h, caches[j], layer)
+                written.append(w)
+            return h, tuple(written)
+
+        layers = jnp.arange(cfg.n_repeats, dtype=jnp.int32)
+        x, written = jax.lax.scan(step, x, (tuple(stacked), layers))
+        return x, [write_decode_step(cfg, kind, c, w, pos)
+                   for kind, c, w in zip(kinds, caches, written)]
 
     has_cache = caches is not None and mode != "train"
 

@@ -148,28 +148,75 @@ def test_ring_buffer_decode_beyond_window():
 # --------------------------------------------------------------------- #
 # scan_layers must not change the math
 # --------------------------------------------------------------------- #
-@pytest.mark.parametrize("arch", ["llama3-8b", "gemma3-1b", "deepseek-v2-236b",
-                                  "recurrentgemma-9b", "seamless-m4t-medium"])
-def test_scan_equals_unrolled(arch):
-    cfg_u = all_configs()[arch].reduced(n_repeats=3, dtype="float32")
+def _scanned_and_unrolled(arch, **overrides):
+    """(cfg, model, params) unrolled, and the same params restacked for
+    the scanned layout."""
+    cfg_u = all_configs()[arch].reduced(n_repeats=3, dtype="float32",
+                                        **overrides)
     cfg_s = cfg_u.with_overrides(scan_layers=True)
     model_u, model_s = build_model(cfg_u), build_model(cfg_s)
     params_u = model_u.init(jax.random.PRNGKey(0))
-
-    # restack unrolled params into the scanned layout
-    def stack(position):
-        return jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs),
-            *[params_u["pattern"][r][position] for r in range(cfg_u.n_repeats)])
-
     params_s = dict(params_u)
-    params_s["pattern"] = [stack(j) for j in range(len(cfg_u.pattern))]
+    params_s["pattern"] = _restack(params_u["pattern"], len(cfg_u.pattern))
+    return (cfg_u, model_u, params_u), (cfg_s, model_s, params_s)
+
+
+def _restack(per_repeat, n_positions):
+    """[repeat][position] pytrees → [position] pytrees with leaves (R, ...)."""
+    return [None if per_repeat[0][j] is None else jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *[r[j] for r in per_repeat])
+            for j in range(n_positions)]
+
+
+@pytest.mark.parametrize("arch", ["llama3-8b", "gemma3-1b", "deepseek-v2-236b",
+                                  "recurrentgemma-9b", "seamless-m4t-medium"])
+def test_scan_equals_unrolled(arch):
+    (cfg_u, model_u, params_u), (_, model_s, params_s) = \
+        _scanned_and_unrolled(arch)
     batch, _ = make_batch(cfg_u, 2, 16)
     hu = model_u.forward(params_u, batch)
     hs = model_s.forward(params_s, batch)
     np.testing.assert_allclose(np.asarray(hu, np.float32),
                                np.asarray(hs, np.float32),
                                atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("arch", ["llama3-8b", "gemma3-1b", "deepseek-v2-236b",
+                                  "recurrentgemma-9b", "seamless-m4t-medium"])
+def test_scan_decode_equals_unrolled(arch):
+    """The scanned decode step reads each layer's slice of the stacked
+    cache and writes every layer's new position in place after the scan;
+    the unrolled step is the reference."""
+    # a window of 8 makes the ring caches wrap inside the decode steps
+    ring = {"sliding_window": 8} if all_configs()[arch].sliding_window else {}
+    (cfg, model_u, params_u), (_, model_s, params_s) = \
+        _scanned_and_unrolled(arch, **ring)
+    B, S, n_dec = 2, 16, 5
+    batch, text_start = make_batch(cfg, B, S)
+    n_pre = S - n_dec
+    pre = dict(batch)
+    pre["tokens"] = batch["tokens"][:, : n_pre - text_start]
+    _, cache_u = model_u.prefill(params_u, pre, max_len=S)
+    _, cache_s = model_s.prefill(params_s, pre, max_len=S)
+    step_s = jax.jit(model_s.decode_step, donate_argnums=(1,))
+
+    def check(a, b):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=1e-4, rtol=1e-4)
+
+    for i in range(n_pre, S):
+        tok = batch["tokens"][:, i - text_start: i - text_start + 1]
+        lu, cache_u = model_u.decode_step(params_u, cache_u, tok,
+                                          jnp.int32(i))
+        ls, cache_s = step_s(params_s, cache_s, tok, jnp.int32(i))
+        check(lu, ls)
+        want = dict(cache_u,
+                    pattern=_restack(cache_u["pattern"], len(cfg.pattern)))
+        assert (jax.tree_util.tree_structure(want)
+                == jax.tree_util.tree_structure(cache_s))
+        jax.tree_util.tree_map(check, want, cache_s)
 
 
 # --------------------------------------------------------------------- #

@@ -13,19 +13,26 @@ prompt):
 * ``flash_attention`` and ``decode_attention`` with ``interpret=False``,
   plus decode at a Llama-style H=32 / Hkv=8 / D=128;
 * one jitted gemma3-1b decode step at published widths and full depth,
-  which must fit one chip.
+  which must fit one chip;
+* the minitron-8b-l16 benchmark's decode step (16 layers, 48 q / 8 kv
+  heads of dim 128, b=8, max_seq 1024, donated cache), which must write
+  its new K/V position into the stacked cache in place: no copy or
+  fresh buffer of the whole stack.
 
 The topology is described inside a module-scoped fixture, never at
 import time: only one process at a time may load the TPU library.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import get_config
 from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
@@ -143,3 +150,69 @@ def test_gemma3_1b_decode_step_fits_one_v5e(one_chip, no_persistent_cache,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
+
+
+# `  [ROOT] %name = dtype[dims]{layout} opcode(operands), ...`
+_HLO_ARRAY = re.compile(
+    r"^\s*(?:ROOT\s+)?%(\S+)\s*=\s*(\w+)\[([\d,]*)\]\S*\s+([\w-]+)\((.*)$")
+
+
+def _hlo_arrays(text):
+    """name → (dtype, dims, opcode, operand names) of every array-valued
+    instruction in a compiled module's text."""
+    out = {}
+    for line in text.splitlines():
+        m = _HLO_ARRAY.match(line)
+        if m:
+            name, dtype, dims, opcode, rest = m.groups()
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            out[name] = (dtype, dims, opcode, re.findall(r"%([\w.-]+)", rest))
+    return out
+
+
+def test_decode_step_writes_kv_cache_in_place_on_v5e(
+        one_chip, no_persistent_cache, monkeypatch, request):
+    """The scanned decode step writes its one new K/V position into the
+    stacked cache in place: the only operation that yields a whole stack
+    is that one-position dynamic-update-slice, and no temporary holds a
+    stack."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    jax.clear_caches()
+    request.addfinalizer(jax.clear_caches)
+    # bench/configs/minitron-8b-l16.json's program overrides
+    cfg = get_config("minitron-8b").with_overrides(
+        n_heads=48, n_repeats=16, use_pallas_kernels=True)
+    assert cfg.scan_layers
+    model = build_model(cfg)
+    B = 8
+    stack = (cfg.n_repeats, B, cfg.n_kv_heads, MAX_SEQ, cfg.resolved_head_dim)
+
+    def place(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                            tree)
+
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: model.init_cache(B, MAX_SEQ)))
+    assert cache["pattern"][0]["k"].shape == stack
+    step = jax.jit(model.decode_step, donate_argnums=(1,))
+    compiled = step.lower(params, cache,
+                          _sds((B, 1), jnp.int32, one_chip),
+                          _sds((), jnp.int32, one_chip)).compile()
+    arrays = _hlo_arrays(compiled.as_text())
+
+    writes = 0
+    for name, (dtype, dims, opcode, operands) in arrays.items():
+        if (dtype, dims) != ("bf16", stack):
+            continue
+        assert opcode in ("parameter", "get-tuple-element",
+                          "dynamic-update-slice"), (name, opcode)
+        if opcode == "dynamic-update-slice":
+            update = arrays[operands[1]][1]
+            # one position of every layer: (R, B, Hkv, 1, Dh)
+            assert update == stack[:3] + (1, stack[4]), (name, update)
+            writes += 1
+    assert writes == 2, writes     # K and V
+
+    kv_pair = 2 * 2 * math.prod(stack)      # bf16 K and V
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < kv_pair, (temp, kv_pair)
