@@ -39,7 +39,8 @@ def main(argv=None) -> int:
         row = {"seed": seed, "correct": out["correct"],
                "failed": out["failed"], "attempted": out["attempted"],
                "check": out["check"], "control": out.get("control"),
-               "setup_s": out["metrics"]["setup_s"]["value"]}
+               "setup_s": out["metrics"]["setup_s"]["value"],
+               "memory_peak_bytes": out["device"]["memory_peak_bytes"]}
         print(json.dumps(row), flush=True)
         for k in ("gap", "err"):
             program.setdefault(k, []).append(out["check"][k]["value"])

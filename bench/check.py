@@ -13,7 +13,8 @@ configuration file's ``limits`` group where it has one:
   over the compared positions.
 
 ``control`` computes the same two numbers for the fp8 control in the
-program's place, at the same sequences and positions.
+program's place, at the same sequences and positions.  Both references
+are the configuration's reference family's ``logits_at``.
 """
 
 from __future__ import annotations
@@ -22,8 +23,6 @@ from dataclasses import dataclass
 from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
-
-from bench.reference import logits_at
 
 
 @dataclass
@@ -69,17 +68,20 @@ def compare(candidate: Sequence[np.ndarray], reference: Sequence[np.ndarray]
     return {"gap": gap, "err": err, "tokens": n}
 
 
-def check(rows: Rows, seed: int, published: Dict) -> Dict[str, float]:
-    """The program's numbers against the float32 reference."""
-    ref = logits_at(seed, published, rows.sequences, rows.positions)
+def check(rows: Rows, seed: int, published: Dict, family
+          ) -> Dict[str, float]:
+    """The program's numbers against the float32 reference of the
+    reference family ``family``."""
+    ref = family.logits_at(seed, published, rows.sequences, rows.positions)
     return compare(rows.program, ref)
 
 
-def control(rows: Rows, seed: int, published: Dict) -> Dict[str, float]:
+def control(rows: Rows, seed: int, published: Dict, family
+            ) -> Dict[str, float]:
     """The fp8 control's numbers against the float32 reference."""
-    ref = logits_at(seed, published, rows.sequences, rows.positions)
-    low = logits_at(seed, published, rows.sequences, rows.positions,
-                    precision="fp8")
+    ref = family.logits_at(seed, published, rows.sequences, rows.positions)
+    low = family.logits_at(seed, published, rows.sequences, rows.positions,
+                           precision="fp8")
     return compare(low, ref)
 
 

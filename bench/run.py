@@ -4,14 +4,16 @@
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: the
 configuration file (published sizes, program overrides, serving
-settings, check limits), the traffic mix under ``bench/traffic/`` and,
-with ``--trace 1``, one reader per per-layer metric under
-``bench/metrics/``.  The run builds the served stack (``client.py``)
-from the seed, profiles and warms every runner cell, offers the mix
-open loop through its lead-in (set-up ends there, with the server
-loaded as in steady state) and then for ``--seconds`` (the window),
-drains, checks what the window produced against the plain reference
-(``check.py``), and prints one JSON line last on standard output.  With ``--trace 1`` the profiler
+settings, check limits), the reference family it names under
+``bench/references/`` (the plain reference and the work counts), the
+traffic mix under ``bench/traffic/`` and, with ``--trace 1``, one
+reader per per-layer metric under ``bench/metrics/``.  The run builds
+the served stack (``client.py``) from the seed, profiles and warms
+every runner cell, offers the mix open loop through its lead-in (set-up
+ends there, with the server loaded as in steady state) and then for
+``--seconds`` (the window), drains, checks what the window produced
+against the plain reference (``check.py``), and prints one JSON line
+last on standard output.  With ``--trace 1`` the profiler
 traces the window's last ``TRACE_S`` seconds and the line carries the
 per-layer metrics instead of the end-to-end ones: those from the
 harness's spans and counters over the whole window, those from the
@@ -28,7 +30,6 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import pathlib  # noqa: E402
@@ -45,6 +46,7 @@ for p in (str(ROOT), str(ROOT / "src")):
 import numpy as np  # noqa: E402
 
 from bench import traffic  # noqa: E402
+from bench.model import family, load_file  # noqa: E402
 
 DRAIN_S = 30.0            # bound on the drain after the window
 TRACE_S = 5.0             # the profiler traces the window's last seconds
@@ -61,6 +63,7 @@ class Cell:
     chips: int
     config_name: str
     config: Dict
+    ref: object               # the configuration's reference family
     mix: traffic.Mix
     end_to_end: List[Dict]
     per_layer: List[Dict]
@@ -74,14 +77,14 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
                        f"choose from {sorted(cells)}")
     w = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
-    cfg_file = root / configs[w["config"]]["file"]
+    config = json.loads((root / configs[w["config"]]["file"]).read_text())
     mix = traffic.load_mix(w["traffic"], root / "bench" / "traffic")
 
     def mine(m: Dict) -> bool:
         return name in m.get("workloads", [name])
 
-    return Cell(name, int(w["chips"]), w["config"],
-                json.loads(cfg_file.read_text()), mix,
+    return Cell(name, int(w["chips"]), w["config"], config,
+                family(config, root), mix,
                 [m for m in spec["end_to_end"] if mine(m)],
                 [m for m in spec["per_layer"] if mine(m)])
 
@@ -147,21 +150,18 @@ def decode_positions(seed: int, prompt: int, max_seq: int) -> List[int]:
 
 
 def load_reader(name: str, root: pathlib.Path = ROOT):
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_file(root / "bench" / "metrics" / f"{name}.py",
+                     f"bench_metric_{name}").read
 
 
 @dataclass
 class Context:
     """What a per-layer metric reader may read."""
-    dims: object                      # reference.Dims
+    dims: object                      # the family's dims_of(config)
     peak: Optional[Dict[str, float]]  # flops, bytes_per_s; None off the chip
     window_s: float
     prompt_tokens: int
+    ref: object = None                # the reference family: work counts
     spans: List = field(default_factory=list)      # plane time, seconds
     decode_pos: Dict[int, List[int]] = field(default_factory=dict)
     compiles_in_window: List[float] = field(default_factory=list)
@@ -187,7 +187,6 @@ def set_up(cell: Cell, seed: int, fault=None) -> SetUp:
     """Weights on the device from the seed, every runner cell built."""
     from bench import model
     from bench.client import ServingSettings, SpanFactory
-    from bench.reference import dims_of
     from bench.tap import Tap
     from repro.models.serve_lm import LmEngine
     cfgfile, mix = cell.config, cell.mix
@@ -197,7 +196,7 @@ def set_up(cell: Cell, seed: int, fault=None) -> SetUp:
         initial_batch=int(sv["initial_batch"]),
         reconfigure_timeout_s=float(sv["reconfigure_timeout_s"]))
     wseed = weight_seed(seed)
-    engine = LmEngine(model.program_config(cfgfile), seed=wseed,
+    engine = LmEngine(model.program_config(cfgfile, cell.ref), seed=wseed,
                       max_seq=int(sv["max_seq"]),
                       default_seq_bucket=mix.prompt_tokens)
     if fault is not None:
@@ -207,8 +206,8 @@ def set_up(cell: Cell, seed: int, fault=None) -> SetUp:
                   seed, engine.default_seq_bucket, engine.max_seq))
     tap.build_cells(settings.max_batch)
     spans = SpanFactory(engine.factory(), time.perf_counter)
-    return SetUp(cfgfile, settings, dims_of(cfgfile), wseed, engine, tap,
-                 spans)
+    return SetUp(cfgfile, settings, cell.ref.dims_of(cfgfile), wseed, engine,
+                 tap, spans)
 
 
 def run_cell(name: str, seed: int, seconds: float, trace_on: bool, *,
@@ -310,7 +309,7 @@ def run_cell(name: str, seed: int, seconds: float, trace_on: bool, *,
     ctx = None
     if trace_on:
         ctx = Context(
-            dims=dims,
+            dims=dims, ref=cell.ref,
             peak=model.peaks(devs[0].device_kind) if devs else None,
             window_s=seconds, prompt_tokens=engine.default_seq_bucket,
             spans=[s.shifted(-t_open)
@@ -331,7 +330,7 @@ def run_cell(name: str, seed: int, seconds: float, trace_on: bool, *,
     gc.collect()
     for a in jax.live_arrays():
         a.delete()
-    numbers = check(rows, wseed, cfgfile)
+    numbers = check(rows, wseed, cfgfile, cell.ref)
     limits = cfgfile["check"]["limits"]
     correct, lines = verdict(numbers, limits,
                              int(cfgfile["check"]["min_tokens"]))
@@ -360,7 +359,7 @@ def run_cell(name: str, seed: int, seconds: float, trace_on: bool, *,
         device["window_s"] = ctx.trace.window_s
         out["breakdown"] = ctx.trace.breakdown()
     if control:
-        out["control"] = control_numbers(rows, wseed, cfgfile)
+        out["control"] = control_numbers(rows, wseed, cfgfile, cell.ref)
     out["check"] = {k: {"value": v, "limit": lim} for k, v, lim in lines}
     for k, v, lim in lines:
         rel = "at least" if k == "tokens" else "limit"

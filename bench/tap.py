@@ -17,6 +17,15 @@ step feeds token 0 at the next position of the cycle
 ``[prompt_bucket, max_seq)``.  So the logits at position ``p`` are those
 of the sequence ``prompt + [0] * (p - prompt_bucket + 1)`` at its last
 position, which the reference computes independently.
+
+That is the tap's contract for every kind of cache, state that a
+position does not address included.  A K/V cell keeps it by nature: a
+step at ``p`` reads the positions before it, and since the cycle last
+wrapped to ``prompt_bucket`` those hold the prompt and the zeros this
+cycle wrote.  A cell that holds recurrent state (a state-space or
+convolution state) has to restart that state from the cell's prompt
+when its cycle wraps to ``prompt_bucket``, or its logits continue
+another sequence than the one the reference is given.
 """
 
 from __future__ import annotations

@@ -2,8 +2,9 @@
 
 It holds one tiny configuration of the Qwen2 shape (d_model 32, two
 layers, bfloat16, as the chip cells are served), one steady mix and the
-benchmark's own metric readers, laid out as a checkout is: the harness
-finds each by name from the root's ``BENCHMARK.json``.
+benchmark's own reference families and metric readers, laid out as a
+checkout is: the harness finds each by name from the root's
+``BENCHMARK.json``.
 """
 
 from __future__ import annotations
@@ -26,6 +27,7 @@ TINY_CONFIG = {
         "hidden_act": "silu", "num_hidden_layers": 2,
         "rope_theta": 1000000.0, "rms_norm_eps": 1e-06, "vocab_size": 256,
         "tie_word_embeddings": True, "torch_dtype": "bfloat16"},
+    "reference": "dense",
     "program": {"registered": "internvl2-1b", "overrides": {
         "d_model": 32, "n_heads": 2, "n_kv_heads": 1, "head_dim": 16,
         "d_ff": 64, "vocab_size": 256, "n_repeats": 2,
@@ -50,6 +52,7 @@ def make_root(tmp: pathlib.Path, *, extra_metrics=()) -> pathlib.Path:
     """A checkout-shaped root under ``tmp`` with the tiny cell."""
     bench = tmp / "bench"
     shutil.copytree(ROOT / "bench" / "metrics", bench / "metrics")
+    shutil.copytree(ROOT / "bench" / "references", bench / "references")
     (bench / "configs").mkdir(parents=True)
     (bench / "traffic").mkdir(parents=True)
     (bench / "configs" / "tiny.json").write_text(json.dumps(TINY_CONFIG))

@@ -10,7 +10,6 @@ import pytest
 
 import bench_tiny
 from bench import model, run
-from bench.reference import dims_of
 from bench.tracing import Trace, covered, load, union
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
@@ -83,7 +82,9 @@ def recorded():
     cfg = json.loads((bench_tiny.ROOT / "bench" / "configs"
                       / "minitron-8b-l16.json").read_text())
     meta = json.loads((DATA / "trace-minitron.meta.json").read_text())
-    ctx = run.Context(dims=dims_of(cfg), peak=model.peaks("TPU v5 lite"),
+    ref = model.family(cfg)
+    ctx = run.Context(dims=ref.dims_of(cfg), ref=ref,
+                      peak=model.peaks("TPU v5 lite"),
                       window_s=meta["window_s"], prompt_tokens=512,
                       decode_pos={int(b): p for b, p in
                                   meta["decode_pos"].items()},
@@ -107,3 +108,43 @@ def test_recorded_trace_holds_both_kernels(recorded):
     assert any("decode_attention" in n for n in names)
     steps = recorded.trace.steps("_decode", "bench.decode")
     assert steps and all(b > 0 for b, _, _ in steps)
+
+
+# Every reader's value on the recorded traces, as the readers gave it
+# when the work counts were still one module of their own; moving them
+# into the reference families changes no reading.
+HELD = {
+    "mfu.prefill": 62.05782458241431,
+    "mfu.prefill.tpot": 62.05782458241431,
+    "mfu.decode": 65.56885316627361,
+    "flash_attention_roofline": 11.91059669241089,
+    "flash_attention_roofline.tpot": 11.91059669241089,
+    "decode_attention_roofline": 84.74946954428893,
+    "device_idle_share": 10.00470739999999,
+}
+HELD_PROGRAM = {
+    "decode_queue_wait_ms": 7.800513066666666,
+    "unit_gate_wait_ms": 0.003631531034482758,
+    "completion_lag_ms": 0.12523413013698628,
+    "decode_lock_wait_ms": 1.5500031102362204,
+    "decode_enqueue_ms": 1.5512789999999999,
+    "gc_pause_share": 0.037883,
+    "gc_pause_share.tpot": 0.037883,
+}
+
+
+@pytest.mark.parametrize("name", sorted(HELD))
+def test_device_readers_hold_their_values(recorded, name):
+    assert _reader(name)(recorded) == HELD[name]
+
+
+@pytest.mark.parametrize("name", sorted(HELD_PROGRAM))
+def test_program_readers_hold_their_values(monkeypatch, name):
+    from bench import program_trace
+    from bench.program_trace import ProgramTrace
+    with open(DATA / "trace-internvl2-program.json") as f:
+        pt = ProgramTrace.from_json(json.load(f))
+    monkeypatch.setattr(program_trace, "latest", lambda: pt)
+    ctx = run.Context(dims=None, peak=None, window_s=10.0, prompt_tokens=0,
+                      trace=Trace(tuple(pt.window_ns)))
+    assert _reader(name)(ctx) == HELD_PROGRAM[name]

@@ -1,18 +1,20 @@
 """The harness's arithmetic on the CPU: the traffic generator, the
 end-to-end metrics (with an injected stall), the peaks table, the work
-counts and the configuration cross-check."""
+counts and the configuration cross-check, through the reference family
+each configuration names."""
 
 import json
 import math
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import bench_tiny
-from bench import model, traffic, work
+from bench import model, traffic
 from bench.client import RequestRecord
 from bench.e2e import nearest_rank, summarize
-from bench.reference import dims_of
 
 ROOT = bench_tiny.ROOT
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -149,28 +151,86 @@ def _cfg(name):
                       .read_text())
 
 
+@pytest.fixture(scope="module")
+def dense():
+    return model.family({"reference": "dense"})
+
+
 def test_program_configs_match_published_keys():
-    mini = model.program_config(_cfg("minitron-8b-l16"))
+    cfg = _cfg("minitron-8b-l16")
+    mini = model.program_config(cfg, model.family(cfg))
     assert (mini.n_heads, mini.n_kv_heads, mini.resolved_head_dim,
             mini.n_layers) == (48, 8, 128, 16)
-    lm = model.program_config(_cfg("internvl2-1b-lm"))
+    cfg = _cfg("internvl2-1b-lm")
+    lm = model.program_config(cfg, model.family(cfg))
     assert (lm.d_model, lm.n_heads, lm.n_layers) == (896, 14, 24)
     bad = _cfg("minitron-8b-l16")
     bad["program"]["overrides"]["n_heads"] = 32      # the repo's 32
     with pytest.raises(ValueError, match="n_heads"):
-        model.program_config(bad)
+        model.program_config(bad, model.family(bad))
 
 
-def test_work_counts():
-    dm = dims_of(_cfg("minitron-8b-l16"))
+def test_work_counts(dense):
+    dm = dense.dims_of(_cfg("minitron-8b-l16"))
     per_layer = 4096 * (48 + 16) * 128 + 48 * 128 * 4096 + 2 * 4096 * 16384
-    assert work.layer_params(dm) == per_layer
-    assert work.weight_bytes(dm) == 2 * (16 * per_layer + 4096 * 256000)
-    w = work.decode(dm, 16, 767)
+    assert dense.layer_params(dm) == per_layer
+    assert dense.weight_bytes(dm) == 2 * (16 * per_layer + 4096 * 256000)
+    w = dense.decode(dm, 16, 767)
     # decode is bound by bytes: weights plus K/V at 768 positions
     assert w.least_s(197e12, 819e9) == w.bytes / 819e9
-    assert w.bytes > work.weight_bytes(dm) + 16 * 768 * 16 * 2 * 8 * 128 * 2
-    att = work.flash_attention(dm, 1, 4)
+    assert w.bytes > dense.weight_bytes(dm) + 16 * 768 * 16 * 2 * 8 * 128 * 2
+    att = dense.flash_attention(dm, 1, 4)
     assert att.flops == 4.0 * 48 * 128 * (4 * 5 // 2) * 16
-    p = work.prefill(dm, 16, 512)
+    p = dense.prefill(dm, 16, 512)
     assert p.least_s(197e12, 819e9) == p.flops / 197e12
+
+
+@pytest.mark.parametrize("change", [
+    {"reference": "dense2"}, {"reference": "../configs/minitron-8b-l16"},
+    {"n_heads": 32}, {"rope_theta": 500000.0}, {"tie_embeddings": True},
+    {"d_ff": 14336}], ids=["unknown", "outside", "n_heads", "rope_theta",
+                           "tie_embeddings", "d_ff"])
+def test_family_refusals(change):
+    """A family with no file under ``bench/references/`` is refused, with
+    the path it looked for; so is a dense file whose program differs
+    from its published keys in one key."""
+    cfg = _cfg("minitron-8b-l16")
+    if "reference" in change:
+        cfg.update(change)
+        with pytest.raises(FileNotFoundError, match="bench/references/"):
+            model.family(cfg)
+        return
+    cfg["program"]["overrides"].update(change)
+    (key,) = change
+    with pytest.raises(ValueError, match=key):
+        model.program_config(cfg, model.family(cfg))
+
+
+def _whole_matrix_attention(mm, q, k, v, precision):
+    """Causal attention over the whole (S, S) score matrix at once."""
+    S, D = q.shape[1], q.shape[-1]
+    s = mm(q, k, precision, "rqhd,rkhd->rhqk") / math.sqrt(D)
+    causal = np.tril(np.ones((S, S), bool))
+    p = jax.nn.softmax(jnp.where(causal[None, None], s, -1e30), axis=-1)
+    return mm(p, v, precision, "rhqk,rkhd->rqhd")
+
+
+@pytest.mark.parametrize("precision", ["f32", "fp8"])
+@pytest.mark.parametrize("block", [32, 64, 256])
+def test_blocked_attention_equals_whole_matrix(dense, precision, block):
+    """The reference's attention in blocks of queries is the attention of
+    the whole score matrix, to float32 rounding, in the control's fp8 as
+    in float32."""
+    rng = np.random.default_rng(block)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 256, 4, 16)), jnp.float32)
+               for _ in range(3))
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(dense.attention(q, k, v, precision, block))
+        want = np.asarray(_whole_matrix_attention(dense._mm, q, k, v,
+                                                    precision))
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+
+
+def test_query_block_divides_the_padded_length(dense):
+    assert [dense.q_block(s) for s in (128, 640, 1024, 8192)] == \
+        [128, 128, 512, 512]
